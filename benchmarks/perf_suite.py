@@ -40,8 +40,6 @@ What is measured:
     repair vs a cold chains re-solve at the headline size;
   * `core.sweep.pareto_frontier`: the warm ζ grid vs cold zeta_sweep, and
     the exact-breakpoint frontier;
-  * `kernels.cost_batch.simulate_batch`: the jitted batch cost kernel
-    (throughput + ≤1e-9 agreement with the numpy closed form);
   * the cluster discrete-event sim with memoized phase costs.
 
 Exit status is nonzero iff any correctness gate fails; timing numbers are
@@ -244,39 +242,6 @@ def gate_warm_start(failures: list[str], *, n_instances: int = 12) -> dict:
                 f"warm-start objective mismatch: instance {t} "
                 f"warm={asg.objective!r} cold={cold.objective!r}")
     return {"instances": n_instances, "bit_identical": n_bit}
-
-
-def gate_jit_cost_kernel(failures: list[str]) -> dict:
-    """kernels.cost_batch.simulate_batch must match the numpy closed form
-    (AnalyticLLMSimulator.simulate) ≤ 1e-9 rel, both KV modes, including
-    window/MoE breakpoint crossings, τout ∈ {0, 1} edges."""
-    try:
-        from repro.kernels import cost_batch
-    except Exception as e:  # noqa: BLE001 — missing jax must not fail CI
-        return {"skipped": f"{type(e).__name__}: {e}"}
-    rng = np.random.default_rng(31)
-    tin = np.concatenate([rng.integers(1, 4096, 24),
-                          [1, 2, 3000, 4095, 4096, 5000]])
-    tout = np.concatenate([rng.integers(1, 4096, 24), [1, 2, 3, 4, 0, 512]])
-    worst = 0.0
-    for name in ("llama2-7b", "mixtral-8x7b", "mistral-7b"):
-        cfg = GATE_CONFIGS[name]()
-        for kv in (True, False):
-            sim = AnalyticLLMSimulator(cfg, batch=4, kv_cache=kv,
-                                       noise_sigma=0.0)
-            e_j, r_j = cost_batch.simulate_batch(sim, tin, tout)
-            for i in range(len(tin)):
-                pb = sim.simulate(int(tin[i]), int(tout[i]))
-                rel = max(abs(e_j[i] - pb.energy_j) / max(abs(pb.energy_j),
-                                                          1e-300),
-                          abs(r_j[i] - pb.runtime_s) / max(abs(pb.runtime_s),
-                                                           1e-300))
-                worst = max(worst, rel)
-                if rel > 1e-9:
-                    failures.append(
-                        f"jit cost kernel mismatch: {name} kv={kv} "
-                        f"tin={tin[i]} tout={tout[i]} rel={rel:.3e}")
-    return {"worst_rel_err": worst, "tolerance": 1e-9}
 
 
 def gate_dvfs_closed_form(failures: list[str]) -> dict:
@@ -1126,7 +1091,6 @@ def run_gates(quick: bool) -> tuple[dict, list[str]]:
             failures, n_instances=8 if quick else 12),
         "warm_start": gate_warm_start(
             failures, n_instances=12 if quick else 25),
-        "jit_cost_kernel": gate_jit_cost_kernel(failures),
         "dvfs_closed_form": gate_dvfs_closed_form(failures),
         "power_conservation": gate_power_conservation(failures),
         "preemption_split": gate_preemption_split(failures),
@@ -1437,38 +1401,6 @@ def bench_pareto(sizes: list[int], failures: list[str]) -> dict:
     return out
 
 
-def bench_jit_cost_kernel(sizes: list[int]) -> dict:
-    """Jitted batch cost kernel throughput: m-query (and m×k) energy/
-    runtime surfaces in one on-device call vs the numpy closed-form loop."""
-    try:
-        from repro.kernels import cost_batch
-    except Exception as e:  # noqa: BLE001
-        return {"skipped": f"{type(e).__name__}: {e}"}
-    cfg = PAPER_ZOO["llama2-7b"]
-    sim = AnalyticLLMSimulator(cfg, batch=4, kv_cache=True, noise_sigma=0.0)
-    out = {}
-    for m in sizes:
-        rng = np.random.default_rng(m)
-        tin = rng.integers(1, 4096, m)
-        tout = rng.integers(1, 4096, m)
-        us_jit, (e_j, r_j) = timed(
-            lambda: cost_batch.simulate_batch(sim, tin, tout), repeats=3)
-        n_ref = min(m, 2000)      # python loop timed on a slice, scaled up;
-        sim._prefill_memo.clear()  # memo-cold, so the loop pays full price
-        sim._decode_memo.clear()
-        t0 = time.perf_counter()
-        for i in range(n_ref):
-            sim.simulate(int(tin[i]), int(tout[i]))
-        us_ref = (time.perf_counter() - t0) * 1e6 * (m / n_ref)
-        out[str(m)] = {
-            "jit_us": us_jit,
-            "numpy_loop_us_scaled": us_ref,
-            "speedup": us_ref / us_jit,
-            "queries_per_s": m / (us_jit * 1e-6),
-        }
-    return out
-
-
 def bench_cluster(sizes: list[int]) -> dict:
     from repro.cluster import (ClusterNode, ZetaOnlinePolicy, poisson_trace,
                                simulate_cluster)
@@ -1581,15 +1513,11 @@ def main(argv: list[str] | None = None) -> int:
             "warm_start_reschedule": bench_warm_start(
                 args.headline_m, failures),
             "pareto_sweep": bench_pareto(sizes, failures),
-            "jit_cost_kernel": bench_jit_cost_kernel(sizes),
             "cluster_sim": bench_cluster(sizes),
         }
         dec = bench["decode_cost_tau4096"]["kv_off"]
         cap = bench["schedule_capacitated"]["headline"]
         ws = bench["warm_start_reschedule"]
-        jit = bench["jit_cost_kernel"]
-        jit_top = (None if "skipped" in jit
-                   else jit[max(jit, key=lambda s: int(s))])
         doc = {
             "suite": "core",
             "created_unix": time.time(),
@@ -1615,10 +1543,6 @@ def main(argv: list[str] | None = None) -> int:
                     ws["warm_reschedule_s"],
                 "warm_start_objective_matches_cold":
                     ws["objective_matches_cold"],
-                "jit_cost_kernel_worst_rel_err":
-                    gates["jit_cost_kernel"].get("worst_rel_err"),
-                "jit_cost_kernel_queries_per_s":
-                    None if jit_top is None else jit_top["queries_per_s"],
                 "sharded_replay_requests_per_min":
                     gates["sharded_replay"]["requests_per_min"],
                 "sharded_replay_equivalent_at_shards":

@@ -13,8 +13,7 @@
 #                            correctness gates for the vectorized hot paths
 #                            (closed-form decode vs chunked reference, fast
 #                            capacitated solver vs min-cost-flow oracle,
-#                            warm-start reschedule vs cold solve, jitted
-#                            batch cost kernel vs the numpy closed form,
+#                            warm-start reschedule vs cold solve,
 #                            DVFS closed-form frequency choice vs a brute-
 #                            force frequency grid, gated-sim energy
 #                            conservation: busy+idle+gated+transition ==

@@ -6,10 +6,6 @@ in interpret mode against the pure-jnp oracles in repro.kernels.ref:
   * ssd_scan         — Mamba-2 SSD chunk scan (quadratic-in-VMEM,
     linear-across-chunks)
   * rglru_scan       — RG-LRU linear recurrence (doubling scan per block)
-  * cost_batch       — jit (x64) + Pallas batch cost kernels: the analytic
-                       energy surface (prefill roofline + exact closed-form
-                       decode integral) over million-query arrays in one
-                       on-device call, ≤1e-9 vs the numpy closed form
 """
 
-from repro.kernels import cost_batch, ops  # noqa: F401
+from repro.kernels import ops  # noqa: F401

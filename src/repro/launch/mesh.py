@@ -7,12 +7,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; older jax only accepts
-    # (shape, axes) and treats every axis as Auto already.
-    axis_type = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

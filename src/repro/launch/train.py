@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.data.workloads import lm_train_batches
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.steps import build_train_step
 from repro.models import get_api
 
@@ -66,6 +67,7 @@ def train(arch, *, steps: int, batch: int, seq: int, lr: float = 3e-4,
 
 
 def main(argv=None) -> int:
+    use_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="qwen3-1.7b-reduced")
     p.add_argument("--steps", type=int, default=100)

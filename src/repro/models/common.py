@@ -10,6 +10,7 @@ matters for the 95-layer dry-runs).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import zlib
 from typing import Callable, Mapping
 
@@ -173,6 +174,16 @@ def _set_path(tree: dict, path: str, value) -> None:
     tree[keys[-1]] = value
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _normal_leaf(key: jax.Array, shape: tuple, scale: float, dtype) -> jax.Array:
+    # One program per leaf, so the f32 draw is freed inside it. Op by op,
+    # dispatch runs ahead of the device and the f32 arrays of many leaves
+    # are alive at once. The barrier stops XLA from folding the scale into
+    # the draw, which would change the weights in the last bit.
+    x = jax.lax.optimization_barrier(jax.random.normal(key, shape, jnp.float32))
+    return (x * scale).astype(dtype)
+
+
 def init_params(defs: ParamTree, key: jax.Array, dtype) -> dict:
     """Materialize parameters from defs (deterministic per path)."""
     params: dict = {}
@@ -183,7 +194,7 @@ def init_params(defs: ParamTree, key: jax.Array, dtype) -> dict:
         elif d.init == "ones":
             val = jnp.ones(d.shape, dtype)
         else:
-            val = (jax.random.normal(sub, d.shape, jnp.float32) * d.scale).astype(dtype)
+            val = _normal_leaf(sub, tuple(d.shape), d.scale, jnp.dtype(dtype))
         _set_path(params, path, val)
     return params
 

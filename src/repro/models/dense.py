@@ -197,13 +197,17 @@ def decode_pass(cfg: ModelConfig, blocks: dict, x: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def train_loss(cfg: ModelConfig, params: dict, batch: dict):
-    tokens, labels = batch["tokens"], batch["labels"]
+def full_logits(cfg: ModelConfig, params: dict, tokens: jax.Array) -> jax.Array:
+    """Logits at every position [B, S, V] from one forward pass."""
     x = embed_tokens(params["embed"], tokens)
     h, _ = forward_full(cfg, params["blocks"], x, window=cfg.window)
     h = rmsnorm(h, params["final_norm"]["w"], cfg.rmsnorm_eps)
-    logits = lm_logits(h, head_matrix(cfg, params), cfg.vocab_size)
-    loss, _ = cross_entropy(logits, labels)
+    return lm_logits(h, head_matrix(cfg, params), cfg.vocab_size)
+
+
+def train_loss(cfg: ModelConfig, params: dict, batch: dict):
+    logits = full_logits(cfg, params, batch["tokens"])
+    loss, _ = cross_entropy(logits, batch["labels"])
     return loss, {}
 
 

@@ -6,6 +6,7 @@
     logits, cache = api.prefill(cfg, params, batch, cache_len=...)
     cache = api.init_cache(cfg, batch_size, cache_len, long_context=...)
     logits, cache = api.decode_step(cfg, params, cache, {"token": ...})
+    logits = api.full_logits(cfg, params, tokens)   # dense and ssm only
 """
 
 from __future__ import annotations
@@ -43,6 +44,7 @@ class ModelAPI:
     prefill: Callable
     init_cache: Callable
     decode_step: Callable
+    full_logits: Callable | None = None   # [B, S, V] from one forward pass
 
     def init_params(self, cfg: ModelConfig, key: jax.Array) -> dict:
         return _init(self.param_defs(cfg), key, cfg.dtype)
@@ -71,6 +73,7 @@ def get_api(cfg_or_family: ModelConfig | str) -> ModelAPI:
         prefill=mod.prefill,
         init_cache=mod.init_cache,
         decode_step=mod.decode_step,
+        full_logits=getattr(mod, "full_logits", None),
     )
 
 

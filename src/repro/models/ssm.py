@@ -240,11 +240,16 @@ def forward_full(cfg: ModelConfig, params: dict, x: jax.Array, *,
     return h, states
 
 
-def train_loss(cfg: ModelConfig, params: dict, batch: dict):
-    x = embed_tokens(params["embed"], batch["tokens"])
+def full_logits(cfg: ModelConfig, params: dict, tokens: jax.Array) -> jax.Array:
+    """Logits at every position [B, S, V] from one forward pass."""
+    x = embed_tokens(params["embed"], tokens)
     h, _ = forward_full(cfg, params, x)
     h = rmsnorm(h, params["final_norm"]["w"], cfg.rmsnorm_eps)
-    logits = lm_logits(h, params["head"], cfg.vocab_size)
+    return lm_logits(h, params["head"], cfg.vocab_size)
+
+
+def train_loss(cfg: ModelConfig, params: dict, batch: dict):
+    logits = full_logits(cfg, params, batch["tokens"])
     loss, _ = cross_entropy(logits, batch["labels"])
     return loss, {}
 

@@ -13,6 +13,9 @@ disabled (§3, §5.1) while production serving uses it:
 
 An optional meter (repro.energy.meter.EnergyMeter) wraps each phase and
 returns joules; GenStats feeds the characterization campaign directly.
+Every program is compiled before the meter starts, once per argument
+signature, and the engine counts those compiles, with their tracing and
+XLA seconds kept apart, outside the metered run time.
 """
 
 from __future__ import annotations
@@ -54,6 +57,37 @@ class GenStats:
         return self.tau_out / self.decode_s if self.decode_s > 0 else float("inf")
 
 
+class _CompiledFn:
+    """A jitted function compiled ahead of time once per argument signature
+    (pytree structure, avals and static arguments), so that compilation is
+    counted and timed apart from the run it precedes. `lower_s` is tracing
+    and lowering, which the persistent cache never skips; `seconds` is the
+    XLA compile, which it can."""
+
+    def __init__(self, fn: Callable, **jit_kwargs):
+        self._jit = jax.jit(fn, **jit_kwargs)
+        self._executables: dict = {}
+        self.count = 0
+        self.lower_s = 0.0
+        self.seconds = 0.0
+
+    def executable(self, *args, **static):
+        leaves, tree = jax.tree.flatten(args)
+        key = (tree, tuple(jax.typeof(x) for x in leaves),
+               tuple(sorted(static.items())))
+        exe = self._executables.get(key)
+        if exe is None:
+            t0 = time.perf_counter()
+            lowered = self._jit.lower(*args, **static)
+            t1 = time.perf_counter()
+            exe = lowered.compile()
+            self.lower_s += t1 - t0
+            self.seconds += time.perf_counter() - t1
+            self.count += 1
+            self._executables[key] = exe
+        return exe
+
+
 class _NullMeter:
     """Measures wall time only; energy reported as 0."""
 
@@ -87,17 +121,37 @@ class InferenceEngine:
         self.meter = meter or _NullMeter()
         self.key = jax.random.PRNGKey(seed)
 
-        self._prefill = jax.jit(
+        self._prefill = _CompiledFn(
             partial(self.api.prefill, cfg),
             static_argnames=("cache_len", "long_context"))
 
-        def _decode(params, cache, token, key):
-            logits, cache = self.api.decode_step(cfg, params, cache,
-                                                 {"token": token})
-            nxt = self.sampler(logits, key)
-            return nxt, cache
+        # closes over locals, not self: a cycle through self would keep the
+        # weights on the device after the engine is dropped, until a gc pass
+        api = self.api
 
-        self._decode = jax.jit(_decode, donate_argnums=(1,))
+        # returns the logits beside the sampled token, so that a check can
+        # hold the served program's logits against a full forward pass
+        def _decode(params, cache, token, key):
+            logits, cache = api.decode_step(cfg, params, cache,
+                                            {"token": token})
+            nxt = sampler(logits, key)
+            return logits, nxt, cache
+
+        self._decode = _CompiledFn(_decode, donate_argnums=(1,))
+
+    @property
+    def compile_count(self) -> int:
+        return self._prefill.count + self._decode.count
+
+    @property
+    def compile_s(self) -> float:
+        """XLA compile seconds, without tracing and lowering."""
+        return self._prefill.seconds + self._decode.seconds
+
+    @property
+    def lower_s(self) -> float:
+        """Tracing and lowering seconds of the compiled programs."""
+        return self._prefill.lower_s + self._decode.lower_s
 
     # ------------------------------------------------------------------
     def _pad_len(self, n: int) -> int:
@@ -123,23 +177,26 @@ class InferenceEngine:
         cache_len = self._pad_len(span)
 
         inputs = {"tokens": tokens, **extra}
+        prefill = self._prefill.executable(
+            self.params, inputs, cache_len=cache_len,
+            long_context=self.long_context)
         (logits, cache), t_prefill, e_prefill = self.meter.measure(
-            lambda: self._prefill(self.params, inputs, cache_len=cache_len,
-                                  long_context=self.long_context))
+            lambda: prefill(self.params, inputs))
 
         stats = GenStats(prefill_s=t_prefill, prefill_energy_j=e_prefill,
                          tau_in=S0, tau_out=max_new)
         out = np.zeros((B, max_new), np.int32)
         self.key, k0 = jax.random.split(self.key)
         token = self.sampler(logits, k0)
+        decode = self._decode.executable(self.params, cache, token, k0)
 
         t0 = time.perf_counter()
         e_total = 0.0
         for t in range(max_new):
             out[:, t] = np.asarray(token)
             self.key, kt = jax.random.split(self.key)
-            (token, cache), dt, de = self.meter.measure(
-                lambda tok=token, kk=kt, c=cache: self._decode(self.params, c, tok, kk))
+            (_, token, cache), dt, de = self.meter.measure(
+                lambda tok=token, kk=kt, c=cache: decode(self.params, c, tok, kk))
             e_total += de
         stats.decode_s = time.perf_counter() - t0
         stats.decode_energy_j = e_total
@@ -161,10 +218,12 @@ class InferenceEngine:
             L = S0 + t
             window = np.asarray(buf[:, :L], np.int32)
             inputs = {"tokens": jnp.asarray(window), **extra}
+            prefill = self._prefill.executable(
+                self.params, inputs, cache_len=L,
+                long_context=self.long_context)
             # full re-forward over the exact prefix — the paper's mode
             (logits, _cache), dt, de = self.meter.measure(
-                lambda i=inputs, lp=L: self._prefill(self.params, i, cache_len=lp,
-                                                     long_context=self.long_context))
+                lambda i=inputs: prefill(self.params, i))
             e_total += de
             if first_step_s is None:
                 first_step_s = dt

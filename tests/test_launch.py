@@ -3,6 +3,8 @@
 The multi-device dry-run itself is exercised in test_dryrun_mini.py (in a
 subprocess with forced host devices)."""
 
+from pathlib import Path
+
 import jax
 import numpy as np
 import pytest
@@ -12,6 +14,7 @@ from repro import shard
 from repro.analysis.hlo import HLOModule, analyze_hlo_text
 from repro.configs import INPUT_SHAPES, get_config
 from repro.launch import sharding as shardrules
+from repro.launch.compile_cache import CHECKOUT_CACHE_DIR, use_compile_cache
 from repro.models import get_api
 from repro.models import cache as cachelib
 
@@ -40,6 +43,29 @@ class TestLegalizeSpec:
     def test_tuple_axes(self):
         out = shard.legalize_spec((256, 7168), P(("data", "model"), None), AXES)
         assert tuple(out) == (("data", "model"),)
+
+
+class TestCompileCache:
+    @pytest.fixture(autouse=True)
+    def _restore(self):
+        before = jax.config.jax_compilation_cache_dir
+        floor = jax.config.jax_persistent_cache_min_compile_time_secs
+        yield
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
+
+    def test_env_var_wins(self, monkeypatch, tmp_path):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert use_compile_cache() == tmp_path
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+
+    def test_fixed_path_in_checkout_otherwise(self, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = use_compile_cache()
+        assert path == CHECKOUT_CACHE_DIR
+        assert path.parent == Path(__file__).resolve().parents[1]
+        assert jax.config.jax_compilation_cache_dir == str(path)
 
 
 class TestRules:

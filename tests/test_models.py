@@ -181,3 +181,29 @@ def test_fp8_kv_cache_decode_close_to_bf16():
     # fp8 storage error is bounded; top-1 token should rarely flip at this scale
     diff = jnp.abs(l8[..., :cfg.vocab_size] - l16[..., :cfg.vocab_size])
     assert float(diff.mean()) < 0.2
+
+
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "mamba2-130m"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_init_params_matches_op_by_op_draw(arch, dtype):
+    """Each leaf is drawn in one jitted program; the weights stay
+    bit-identical to drawing, scaling and casting op by op."""
+    import zlib
+
+    from repro.models.common import _flatten_defs
+
+    cfg, api = reduced(arch)
+    cfg = cfg.replace(param_dtype=dtype)
+    key = jax.random.PRNGKey(7)
+    got = dict(jax.tree_util.tree_flatten_with_path(api.init_params(cfg, key))[0])
+    n_drawn = 0
+    for path, d in _flatten_defs(api.param_defs(cfg)):
+        if d.init in ("zeros", "ones"):
+            continue
+        sub = jax.random.fold_in(key, zlib.crc32(path.encode()))
+        want = (jax.random.normal(sub, d.shape, jnp.float32) * d.scale).astype(cfg.dtype)
+        leaf = got[tuple(jax.tree_util.DictKey(k) for k in path.split("/"))]
+        assert leaf.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(leaf), np.asarray(want))
+        n_drawn += 1
+    assert n_drawn > 3

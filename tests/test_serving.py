@@ -1,5 +1,7 @@
 """Serving engine + router integration tests."""
 
+import gc
+
 import jax
 import numpy as np
 import pytest
@@ -8,6 +10,7 @@ from repro.core.energy_model import AccuracyModel, BilinearModel, LLMProfile
 from repro.energy.meter import WallClockMeter
 from repro.models import get_api
 from repro.serving import EnergyAwareRouter, InferenceEngine, Request, Sampler
+from repro.serving.decode_check import decode_logit_errors
 from helpers import reduced
 
 
@@ -88,3 +91,60 @@ class TestRouter:
         pe = router_e.route(list(reqs))
         pa = router_a.route(list(reqs))
         assert len(pe.per_model["small"]) > len(pa.per_model["small"])
+
+
+class TestDecodeCheck:
+    """Cached decode vs one full forward pass, through the engine's own
+    programs, as the chip smoke runs it at published widths."""
+
+    # reduced widths on the CPU: f32 agrees to rounding; bf16 reads below 0.01
+    LIMITS = {"float32": 1e-4, "bfloat16": 0.05}
+
+    @pytest.mark.parametrize("arch", ["qwen3-1.7b", "mamba2-130m"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_passes_and_control_fails(self, arch, dtype):
+        cfg, api = reduced(arch)
+        cfg = cfg.replace(param_dtype=dtype)
+        eng = InferenceEngine(cfg, api.init_params(cfg, jax.random.PRNGKey(0)),
+                              bucket=16)
+        toks = np.random.default_rng(0).integers(
+            1, cfg.vocab_size, (4, 32)).astype(np.int32)
+        r = decode_logit_errors(eng, toks, 16)
+        limit = self.LIMITS[dtype]
+        assert r["error"] <= limit
+        assert r["missing_token"] > limit
+        # an SSM's decode reads no position; attention's reads it for RoPE
+        # and the cache slot
+        if cfg.family == "ssm":
+            assert r["position_shift"] is None
+        else:
+            assert r["position_shift"] > limit
+        # the check ran the served programs: two prefill lengths, one decode
+        assert eng.compile_count == 3
+
+
+class TestCompiledFn:
+    def test_compiles_once_per_signature(self):
+        cfg, api = reduced("qwen3-1.7b")
+        params = api.init_params(cfg, jax.random.PRNGKey(0))
+        eng = InferenceEngine(cfg, params, kv_cache=False, bucket=8)
+        toks = np.ones((1, 8), np.int32)
+        eng.generate({"tokens": toks}, 3)
+        assert eng.compile_count == 3          # prefix lengths 8, 9, 10
+        eng.generate({"tokens": toks}, 3)
+        assert eng.compile_count == 3 and eng.compile_s > 0
+
+    def test_dropped_engine_frees_weights_without_gc(self):
+        cfg, api = reduced("qwen3-1.7b")
+        live = lambda: sum(a.nbytes for a in jax.live_arrays())  # noqa: E731
+        before = live()
+        gc.disable()
+        try:
+            eng = InferenceEngine(cfg, api.init_params(cfg, jax.random.PRNGKey(0)),
+                                  bucket=8)
+            eng.generate({"tokens": np.ones((1, 8), np.int32)}, 2)
+            assert live() > before
+            del eng
+            assert live() == before
+        finally:
+            gc.enable()
