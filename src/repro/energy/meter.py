@@ -7,7 +7,8 @@ roofline energy for a declared cost, for use where wall time on CPU is not
 representative of the target accelerator.
 
 Both expose  measure(fn) -> (result, seconds, joules)  — the engine's
-metering contract.
+metering contract — and time `fn` through `timed`, which opens the
+`engine.wait` span where the host blocks on the device.
 """
 
 from __future__ import annotations
@@ -17,6 +18,18 @@ import time
 import jax
 
 from repro.energy.hardware import GENERIC_HOST, HostSpec, Node
+
+WAIT = "engine.wait"
+
+
+def timed(fn):
+    """Runs `fn` and blocks until its outputs are ready on the device, the
+    block inside the `engine.wait` span -> (outputs, wall seconds)."""
+    t0 = time.perf_counter()
+    out = fn()
+    with jax.profiler.TraceAnnotation(WAIT):
+        out = jax.block_until_ready(out)
+    return out, time.perf_counter() - t0
 
 
 class WallClockMeter:
@@ -32,10 +45,7 @@ class WallClockMeter:
         return self.host.idle_w / 4.0 + self.host.active_w_per_core * self.host.serving_cores
 
     def measure(self, fn):
-        t0 = time.perf_counter()
-        out = fn()
-        out = jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
+        out, dt = timed(fn)
         joules = self.power_w * dt
         self.total_s += dt
         self.total_j += joules
@@ -53,10 +63,7 @@ class ModeledMeter:
         self.total_j = 0.0
 
     def measure(self, fn):
-        t0 = time.perf_counter()
-        out = fn()
-        out = jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
+        out, dt = timed(fn)
         flops, bytes_ = self.cost_fn()
         a = self.node.accel
         joules = (a.idle_w * self.node.n_accel * dt

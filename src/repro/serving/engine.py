@@ -16,6 +16,21 @@ returns joules; GenStats feeds the characterization campaign directly.
 Every program is compiled before the meter starts, once per argument
 signature, and the engine counts those compiles, with their tracing and
 XLA seconds kept apart, outside the metered run time.
+
+For operators: every `generate` call writes host spans into the JAX
+profiler's trace, always; with no trace being recorded a span costs about
+half a microsecond of host time (on a TPU v5e host). `engine.generate` is
+one call (one batch), with its `B`, `prompt_len`, `max_new`, `cache_len`
+and `call` (the engine's count of calls, which every span of the batch
+shares by nesting). Inside it, `engine.prefill` is everything before the
+first decode step and `engine.decode` the decode loop, made of one
+`engine.step` per decoded position; in a step, `engine.fetch` is the
+device-to-host copy of the token and `engine.wait` the meter's block on
+the device. `engine.compile` (arg `program`) is a new signature lowered
+and compiled. Wrapping a serve in `jax.profiler.trace(dir)` records these
+spans beside the chip's op and program events, for TensorBoard or
+Perfetto; the device's events may run a millisecond or so ahead of the
+host's clock there.
 """
 
 from __future__ import annotations
@@ -30,9 +45,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.energy.meter import WAIT, timed
 from repro.models import get_api
 from repro.models.common import ModelConfig
 from repro.serving.sampler import Sampler
+
+# host span names (WAIT, "engine.wait", is opened by the meters' `timed`)
+GENERATE = "engine.generate"
+PREFILL = "engine.prefill"
+DECODE = "engine.decode"
+STEP = "engine.step"
+FETCH = "engine.fetch"
+COMPILE = "engine.compile"
+SPANS = (GENERATE, PREFILL, DECODE, STEP, FETCH, WAIT, COMPILE)
 
 
 @dataclasses.dataclass
@@ -52,20 +77,18 @@ class GenStats:
     def energy_j(self) -> float:
         return self.prefill_energy_j + self.decode_energy_j
 
-    @property
-    def tokens_per_s(self) -> float:
-        return self.tau_out / self.decode_s if self.decode_s > 0 else float("inf")
-
 
 class _CompiledFn:
     """A jitted function compiled ahead of time once per argument signature
     (pytree structure, avals and static arguments), so that compilation is
     counted and timed apart from the run it precedes. `lower_s` is tracing
     and lowering, which the persistent cache never skips; `seconds` is the
-    XLA compile, which it can."""
+    XLA compile, which it can. `name` is the program's, for the
+    `engine.compile` span."""
 
-    def __init__(self, fn: Callable, **jit_kwargs):
+    def __init__(self, fn: Callable, name: str, **jit_kwargs):
         self._jit = jax.jit(fn, **jit_kwargs)
+        self.name = name
         self._executables: dict = {}
         self.count = 0
         self.lower_s = 0.0
@@ -77,12 +100,13 @@ class _CompiledFn:
                tuple(sorted(static.items())))
         exe = self._executables.get(key)
         if exe is None:
-            t0 = time.perf_counter()
-            lowered = self._jit.lower(*args, **static)
-            t1 = time.perf_counter()
-            exe = lowered.compile()
-            self.lower_s += t1 - t0
-            self.seconds += time.perf_counter() - t1
+            with jax.profiler.TraceAnnotation(COMPILE, program=self.name):
+                t0 = time.perf_counter()
+                lowered = self._jit.lower(*args, **static)
+                t1 = time.perf_counter()
+                exe = lowered.compile()
+                self.lower_s += t1 - t0
+                self.seconds += time.perf_counter() - t1
             self.count += 1
             self._executables[key] = exe
         return exe
@@ -92,10 +116,8 @@ class _NullMeter:
     """Measures wall time only; energy reported as 0."""
 
     def measure(self, fn):
-        t0 = time.perf_counter()
-        out = fn()
-        out = jax.block_until_ready(out)
-        return out, time.perf_counter() - t0, 0.0
+        out, dt = timed(fn)
+        return out, dt, 0.0
 
 
 class InferenceEngine:
@@ -120,9 +142,10 @@ class InferenceEngine:
         self.long_context = long_context
         self.meter = meter or _NullMeter()
         self.key = jax.random.PRNGKey(seed)
+        self.calls = 0
 
         self._prefill = _CompiledFn(
-            partial(self.api.prefill, cfg),
+            partial(self.api.prefill, cfg), "prefill",
             static_argnames=("cache_len", "long_context"))
 
         # closes over locals, not self: a cycle through self would keep the
@@ -137,7 +160,7 @@ class InferenceEngine:
             nxt = sampler(logits, key)
             return logits, nxt, cache
 
-        self._decode = _CompiledFn(_decode, donate_argnums=(1,))
+        self._decode = _CompiledFn(_decode, "decode", donate_argnums=(1,))
 
     @property
     def compile_count(self) -> int:
@@ -165,77 +188,92 @@ class InferenceEngine:
     def generate(self, batch: dict, max_new_tokens: int) -> tuple[np.ndarray, GenStats]:
         """batch: {"tokens": [B, S0] int32, (+"patches"/"frames")}.
         Returns (generated [B, max_new_tokens] int32, stats)."""
+        B, S0 = np.shape(batch["tokens"])
         if self.kv_cache:
-            return self._generate_cached(batch, max_new_tokens)
-        return self._generate_uncached(batch, max_new_tokens)
+            span = S0 + max_new_tokens + (
+                self.cfg.n_patches if self.cfg.family == "vlm" else 0)
+            cache_len = self._pad_len(span)
+        else:
+            cache_len = S0 + max_new_tokens - 1      # the longest re-forward
+        self.calls += 1
+        with jax.profiler.TraceAnnotation(
+                GENERATE, B=B, prompt_len=S0, max_new=max_new_tokens,
+                cache_len=cache_len, call=self.calls):
+            if self.kv_cache:
+                return self._generate_cached(batch, max_new_tokens, cache_len)
+            return self._generate_uncached(batch, max_new_tokens)
 
-    def _generate_cached(self, batch, max_new):
-        tokens = jnp.asarray(batch["tokens"], jnp.int32)
-        B, S0 = tokens.shape
-        extra = self._extra_inputs(batch)
-        span = S0 + max_new + (self.cfg.n_patches if self.cfg.family == "vlm" else 0)
-        cache_len = self._pad_len(span)
+    def _generate_cached(self, batch, max_new, cache_len):
+        with jax.profiler.TraceAnnotation(PREFILL):
+            tokens = jnp.asarray(batch["tokens"], jnp.int32)
+            B, S0 = tokens.shape
+            inputs = {"tokens": tokens, **self._extra_inputs(batch)}
+            prefill = self._prefill.executable(
+                self.params, inputs, cache_len=cache_len,
+                long_context=self.long_context)
+            (logits, cache), t_prefill, e_prefill = self.meter.measure(
+                lambda: prefill(self.params, inputs))
 
-        inputs = {"tokens": tokens, **extra}
-        prefill = self._prefill.executable(
-            self.params, inputs, cache_len=cache_len,
-            long_context=self.long_context)
-        (logits, cache), t_prefill, e_prefill = self.meter.measure(
-            lambda: prefill(self.params, inputs))
+            stats = GenStats(prefill_s=t_prefill, prefill_energy_j=e_prefill,
+                             tau_in=S0, tau_out=max_new)
+            out = np.zeros((B, max_new), np.int32)
+            self.key, k0 = jax.random.split(self.key)
+            token = self.sampler(logits, k0)
+            decode = self._decode.executable(self.params, cache, token, k0)
 
-        stats = GenStats(prefill_s=t_prefill, prefill_energy_j=e_prefill,
-                         tau_in=S0, tau_out=max_new)
-        out = np.zeros((B, max_new), np.int32)
-        self.key, k0 = jax.random.split(self.key)
-        token = self.sampler(logits, k0)
-        decode = self._decode.executable(self.params, cache, token, k0)
-
-        t0 = time.perf_counter()
-        e_total = 0.0
-        for t in range(max_new):
-            out[:, t] = np.asarray(token)
-            self.key, kt = jax.random.split(self.key)
-            (_, token, cache), dt, de = self.meter.measure(
-                lambda tok=token, kk=kt, c=cache: decode(self.params, c, tok, kk))
-            e_total += de
-        stats.decode_s = time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation(DECODE):
+            t0 = time.perf_counter()
+            e_total = 0.0
+            for t in range(max_new):
+                with jax.profiler.TraceAnnotation(STEP):
+                    with jax.profiler.TraceAnnotation(FETCH):
+                        out[:, t] = np.asarray(token)
+                    self.key, kt = jax.random.split(self.key)
+                    (_, token, cache), dt, de = self.meter.measure(
+                        lambda: decode(self.params, cache, token, kt))
+                    e_total += de
+            stats.decode_s = time.perf_counter() - t0
         stats.decode_energy_j = e_total
         return out, stats
 
     def _generate_uncached(self, batch, max_new):
-        tokens = np.asarray(batch["tokens"], np.int32)
-        B, S0 = tokens.shape
-        extra = self._extra_inputs(batch)
-        buf = np.zeros((B, S0 + max_new), np.int32)
-        buf[:, :S0] = tokens
-
-        stats = GenStats(tau_in=S0, tau_out=max_new)
-        out = np.zeros((B, max_new), np.int32)
-        e_total = 0.0
-        t_start = time.perf_counter()
-        first_step_s = None
-        for t in range(max_new):
+        def step(t):
+            """One full re-forward over the exact prefix — the paper's mode —
+            and its sampled token -> (seconds, joules) of the forward."""
             L = S0 + t
             window = np.asarray(buf[:, :L], np.int32)
             inputs = {"tokens": jnp.asarray(window), **extra}
             prefill = self._prefill.executable(
                 self.params, inputs, cache_len=L,
                 long_context=self.long_context)
-            # full re-forward over the exact prefix — the paper's mode
             (logits, _cache), dt, de = self.meter.measure(
-                lambda i=inputs: prefill(self.params, i))
-            e_total += de
-            if first_step_s is None:
-                first_step_s = dt
+                lambda: prefill(self.params, inputs))
             self.key, kt = jax.random.split(self.key)
-            token = np.asarray(self.sampler(logits, kt))
+            token = self.sampler(logits, kt)
+            with jax.profiler.TraceAnnotation(FETCH):
+                token = np.asarray(token)
             out[:, t] = token
             buf[:, L] = token
-        total = time.perf_counter() - t_start
-        # attribute the first full-prefix pass as "prefill", rest as decode
-        stats.prefill_s = first_step_s or 0.0
-        stats.decode_s = total - stats.prefill_s
-        stats.prefill_energy_j = 0.0
+            return dt, de
+
+        # the first full-prefix pass is "prefill", the rest decode
+        with jax.profiler.TraceAnnotation(PREFILL):
+            tokens = np.asarray(batch["tokens"], np.int32)
+            B, S0 = tokens.shape
+            extra = self._extra_inputs(batch)
+            buf = np.zeros((B, S0 + max_new), np.int32)
+            buf[:, :S0] = tokens
+            out = np.zeros((B, max_new), np.int32)
+            stats = GenStats(tau_in=S0, tau_out=max_new)
+            t_start = time.perf_counter()
+            e_total = 0.0
+            if max_new:
+                stats.prefill_s, e_total = step(0)
+        with jax.profiler.TraceAnnotation(DECODE):
+            for t in range(1, max_new):
+                with jax.profiler.TraceAnnotation(STEP):
+                    e_total += step(t)[1]
+            stats.decode_s = time.perf_counter() - t_start - stats.prefill_s
         stats.decode_energy_j = e_total
         return out, stats
 
