@@ -197,15 +197,20 @@ def mamba_block_full(cfg: ModelConfig, pl: dict, x: jax.Array):
 
 
 def mamba_block_decode(cfg: ModelConfig, pl: dict, x: jax.Array,
-                       state: jax.Array, conv_state: jax.Array):
-    """One-token Mamba-2 step.  x [B,d]; state [B,H,P,N] f32;
-    conv_state [B,K-1,cc]."""
+                       states: jax.Array, convs: jax.Array, layer: jax.Array):
+    """One-token Mamba-2 step of `layer`.  x [B,d]; states [L,B,H,P,N] f32
+    and convs [L,B,K-1,cc] are the whole stacks, whose `layer` slices are
+    updated in place.  The output reads the new state back from the stack:
+    computed beside the update, it would make XLA stage the new state in a
+    buffer of its own (see `decode_step`).  Returns (y, states, convs)."""
     Bsz = x.shape[0]
     H, P, N = cfg.ssm_nheads, cfg.ssm_headdim, cfg.ssm_state
     z = jnp.einsum("bd,dk->bk", x, pl["in_proj"])
     zg, xbc, dt_raw = _split_proj(cfg, z)
-    xbc, conv_state = _causal_conv(xbc[:, None], pl["conv_w"], pl["conv_b"],
-                                   state=conv_state)
+    xbc, conv_state = _causal_conv(
+        xbc[:, None], pl["conv_w"], pl["conv_b"],
+        state=jax.lax.dynamic_index_in_dim(convs, layer, keepdims=False))
+    convs = jax.lax.dynamic_update_index_in_dim(convs, conv_state, layer, 0)
     xbc = xbc[:, 0]
     x_ssm = xbc[..., : cfg.d_inner].reshape(Bsz, H, P)
     B_, C_ = _broadcast_groups(cfg, xbc[..., cfg.d_inner:])  # [B,H,N]
@@ -213,13 +218,16 @@ def mamba_block_decode(cfg: ModelConfig, pl: dict, x: jax.Array,
     decay = jnp.exp(dt * A)                                  # [B,H]
     upd = jnp.einsum("bhp,bhn->bhpn", (x_ssm * dt[..., None].astype(x_ssm.dtype)).astype(jnp.float32),
                      B_.astype(jnp.float32))
-    state = state * decay[:, :, None, None] + upd
+    state = jax.lax.dynamic_index_in_dim(states, layer, keepdims=False)
+    states = jax.lax.dynamic_update_index_in_dim(
+        states, state * decay[:, :, None, None] + upd, layer, 0)
+    state = jax.lax.dynamic_index_in_dim(states, layer, keepdims=False)
     y = jnp.einsum("bhpn,bhn->bhp", state, C_.astype(jnp.float32)).astype(x.dtype)
     y = y + pl["D"].astype(y.dtype)[None, :, None] * x_ssm
     y = y.reshape(Bsz, cfg.d_inner)
     y = y * jax.nn.silu(zg.astype(jnp.float32)).astype(y.dtype)
     y = rmsnorm(y, pl["norm_w"], cfg.rmsnorm_eps)
-    return jnp.einsum("bk,kd->bd", y, pl["out_proj"]), state, conv_state
+    return jnp.einsum("bk,kd->bd", y, pl["out_proj"]), states, convs
 
 
 # ---------------------------------------------------------------------------
@@ -275,17 +283,30 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int = 0, *,
 
 
 def decode_step(cfg: ModelConfig, params: dict, cache, batch: dict):
+    """One token through every layer, updating the donated cache in place.
+
+    The f32 state stack is updated in place, one layer slice at a time, in
+    a fully unrolled layer loop: with each layer's index a constant, XLA
+    fuses the read, the update and the write of a layer's state into one
+    pass. As scan xs/ys (what the dense `decode_pass` does with its KV
+    cache), XLA builds the new stack in a fresh buffer and then copies all
+    of it into the donated output; in a rolled loop it stages each layer's
+    new state in a buffer of its own. Either costs one more read and write
+    of the whole state a step. A write at a layer index would make GSPMD
+    gather a stack sharded over "layers"; no served path shards them."""
     token = batch["token"]
-    x = jnp.take(params["embed"], token, axis=0)
+    h = jnp.take(params["embed"], token, axis=0)
 
-    def body(carry, inp):
-        h, = carry,
-        pl, st, cv = inp
-        y, st, cv = mamba_block_decode(cfg, pl, rmsnorm(h, pl["ln"]["w"], cfg.rmsnorm_eps), st, cv)
-        return h + y, (st, cv)
+    def body(carry, pl):
+        h, states, convs, layer = carry
+        y, states, convs = mamba_block_decode(
+            cfg, pl, rmsnorm(h, pl["ln"]["w"], cfg.rmsnorm_eps), states, convs,
+            layer)
+        return (h + y, states, convs, layer + 1), None
 
-    h, (states, convs) = jax.lax.scan(body, x,
-                                      (params["blocks"], cache.state, cache.conv))
+    (h, states, convs, _), _ = jax.lax.scan(
+        body, (h, cache.state, cache.conv, jnp.zeros((), jnp.int32)),
+        params["blocks"], unroll=True)
     h = rmsnorm(h, params["final_norm"]["w"], cfg.rmsnorm_eps)
     logits = lm_logits(h, params["head"], cfg.vocab_size)
     return logits, cachelib.SSMCache(convs, states, cache.pos + 1)

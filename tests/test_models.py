@@ -207,3 +207,73 @@ def test_init_params_matches_op_by_op_draw(arch, dtype):
         np.testing.assert_array_equal(np.asarray(leaf), np.asarray(want))
         n_drawn += 1
     assert n_drawn > 3
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_ssm_decode_in_place_matches_scan_over_states(dtype):
+    """ssm.decode_step updates the state and conv stacks in place; it must
+    match, bit for bit, a step that passes them through a layer scan as
+    xs/ys, over several steps from a prefilled cache."""
+    from repro.models import cache as cachelib
+    from repro.models.common import lm_logits, rmsnorm
+    from repro.models.ssm import (_broadcast_groups, _causal_conv,
+                                  _split_proj, _ssm_params)
+
+    def block(cfg, pl, x, state, conv_state):
+        Bsz = x.shape[0]
+        H, P = cfg.ssm_nheads, cfg.ssm_headdim
+        z = jnp.einsum("bd,dk->bk", x, pl["in_proj"])
+        zg, xbc, dt_raw = _split_proj(cfg, z)
+        xbc, conv_state = _causal_conv(xbc[:, None], pl["conv_w"],
+                                       pl["conv_b"], state=conv_state)
+        xbc = xbc[:, 0]
+        x_ssm = xbc[..., : cfg.d_inner].reshape(Bsz, H, P)
+        B_, C_ = _broadcast_groups(cfg, xbc[..., cfg.d_inner:])
+        A, dt = _ssm_params(cfg, pl, dt_raw)
+        decay = jnp.exp(dt * A)
+        upd = jnp.einsum(
+            "bhp,bhn->bhpn",
+            (x_ssm * dt[..., None].astype(x_ssm.dtype)).astype(jnp.float32),
+            B_.astype(jnp.float32))
+        state = state * decay[:, :, None, None] + upd
+        y = jnp.einsum("bhpn,bhn->bhp", state,
+                       C_.astype(jnp.float32)).astype(x.dtype)
+        y = y + pl["D"].astype(y.dtype)[None, :, None] * x_ssm
+        y = y.reshape(Bsz, cfg.d_inner)
+        y = y * jax.nn.silu(zg.astype(jnp.float32)).astype(y.dtype)
+        y = rmsnorm(y, pl["norm_w"], cfg.rmsnorm_eps)
+        return jnp.einsum("bk,kd->bd", y, pl["out_proj"]), state, conv_state
+
+    def decode_xs_ys(cfg, params, cache, token):
+        x = jnp.take(params["embed"], token, axis=0)
+
+        def body(h, inp):
+            pl, st, cv = inp
+            y, st, cv = block(
+                cfg, pl, rmsnorm(h, pl["ln"]["w"], cfg.rmsnorm_eps), st, cv)
+            return h + y, (st, cv)
+
+        h, (states, convs) = jax.lax.scan(
+            body, x, (params["blocks"], cache.state, cache.conv))
+        h = rmsnorm(h, params["final_norm"]["w"], cfg.rmsnorm_eps)
+        return (lm_logits(h, params["head"], cfg.vocab_size),
+                cachelib.SSMCache(convs, states, cache.pos + 1))
+
+    cfg, api = reduced("mamba2-130m")
+    cfg = cfg.replace(param_dtype=dtype)
+    params = api.init_params(cfg, jax.random.PRNGKey(5))
+    batch = make_batch(cfg, 3, 16, with_labels=False)
+    _, cache = api.prefill(cfg, params, batch)
+    got_step = jax.jit(
+        lambda p, c, t: api.decode_step(cfg, p, c, {"token": t}))
+    want_step = jax.jit(lambda p, c, t: decode_xs_ys(cfg, p, c, t))
+    got = want = cache
+    token = batch["tokens"][:, -1]
+    for _ in range(4):
+        got_logits, got = got_step(params, got, token)
+        want_logits, want = want_step(params, want, token)
+        for a, b in [(got_logits, want_logits), (got.state, want.state),
+                     (got.conv, want.conv), (got.pos, want.pos)]:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        token = jnp.argmax(got_logits, -1).astype(jnp.int32)
+    assert got.state.dtype == jnp.float32 and float(jnp.abs(got.state).max()) > 0

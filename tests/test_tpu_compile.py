@@ -4,12 +4,14 @@ The engine's prefill and decode programs for qwen3-1.7b and mamba2-130m at
 published widths go through the TPU compiler, which refuses what the chip
 cannot run, and each program's arguments, temporaries and outputs must fit
 the chip's 16 GB. A compile is not a run: nothing here says anything about
-results or times.
+results or times. One rehearsal also holds the compiled structure of
+mamba2's decode: its f32 state stack is updated in place.
 """
 
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -83,3 +85,49 @@ def test_decode_compiles_for_one_chip(one_chip, arch):
     key = _on(one_chip, (2,), jnp.uint32)
     compiled = eng._decode.executable(eng.params, cache, token, key)
     _fits(compiled)
+
+
+def _unfused_ops(hlo: str) -> list[str]:
+    """Instructions of the computations that no fusion calls: each result
+    is a buffer of its own in device memory."""
+    fused = set(re.findall(r"kind=k\w+, calls=(%[\w.\-]+)", hlo))
+    ops, keep = [], False
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\{$", line)
+        if head:
+            keep = head.group(1) not in fused
+        elif keep and " = " in line:
+            ops.append(line.strip())
+    return ops
+
+
+def test_ssm_decode_updates_state_in_place(one_chip):
+    """mamba2-130m's decode program at the benchmark cell's batch (64) and
+    cache length writes the donated f32 state stack in place: no copy of
+    the whole stack, temporaries far below one stack, the output state
+    aliased to the input's buffer, and no fusion that stages a layer's
+    updated state in a buffer of its own (each layer's update is fused
+    into its in-place write)."""
+    eng = _engine("mamba2-130m", one_chip)
+    batch = 64
+    cache = jax.tree.map(
+        lambda s: _on(one_chip, s.shape, s.dtype),
+        jax.eval_shape(lambda: eng.api.init_cache(eng.cfg, batch, 2048)))
+    token = _on(one_chip, (batch,), jnp.int32)
+    key = _on(one_chip, (2,), jnp.uint32)
+    compiled = eng._decode.executable(eng.params, cache, token, key)
+    _fits(compiled)
+
+    state = cache.state
+    state_bytes = state.size * state.dtype.itemsize
+    hlo = compiled.as_text()
+    stack = "f32[" + ",".join(map(str, state.shape)) + "]"
+    copies = re.findall(rf"= {re.escape(stack)}\S* copy(?:-start)?\(", hlo)
+    assert not copies, copies
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 0.1 * state_bytes, m.temp_size_in_bytes
+    assert m.alias_size_in_bytes >= state_bytes, m.alias_size_in_bytes
+    layer = re.compile(r"f32\[(1,)?" + ",".join(map(str, state.shape[1:])) + r"\]")
+    staged = [op for op in _unfused_ops(hlo)
+              if " fusion(" in op and layer.search(op.split(" fusion(")[0])]
+    assert not staged, staged
