@@ -1,6 +1,15 @@
-"""Attention primitives: chunked full-sequence attention (never materializes
-the [S, S] score matrix for long sequences) and single-token decode
+"""Attention primitives: full-sequence attention and single-token decode
 attention over a cache.
+
+Full-sequence attention has two implementations of one algorithm. The jnp
+one scans over query chunks of `chunk_q` where the query length divides
+into them, and forms each chunk's f32 scores [chunk_q, Sk] against every
+key (the whole [Sq, Sk] otherwise); it is the path on every platform but
+TPU and for every call the kernel does not take, and the kernel's oracle.
+On TPU, causal self-attention whose length and head size divide into
+128 runs as a flash kernel (the splash attention kernel bundled with JAX):
+scores stay in the core's memory, key blocks above the diagonal are
+skipped, and the softmax is online.
 
 The decode path is the serving hot spot the paper measures; the Pallas
 flash-decode kernel in repro.kernels targets it on TPU, while this module
@@ -9,8 +18,11 @@ provides the portable jnp implementation (also the kernel's oracle).
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from repro import shard
 
@@ -61,9 +73,26 @@ def full_attention(
     """Full-sequence attention.
 
     q: [B, Sq, Hq, D]; k, v: [B, Sk, Hkv, D] (Hq % Hkv == 0).
-    Returns [B, Sq, Hq, D].  When Sq > chunk_q and divisible, scans over
-    query chunks so peak score memory is [B, Hq, chunk_q, Sk].
+    Returns [B, Sq, Hq, D].  Lowered for TPU, causal self-attention with no
+    window, soft cap or offset, whose length and head size divide into 128,
+    runs as the flash kernel (`causal_flash_attention`); everything else,
+    and every other platform, runs the jnp path (`chunked_attention`).
     """
+    Sq, D = q.shape[1], q.shape[3]
+    jnp_path = partial(chunked_attention, causal=causal, window=window,
+                       q_offset=q_offset, chunk_q=chunk_q, softcap=softcap)
+    if (causal and not window and not softcap and q_offset == 0
+            and Sq == k.shape[1] and Sq % 128 == 0 and D % 128 == 0):
+        return jax.lax.platform_dependent(
+            q, k, v, tpu=causal_flash_attention, default=jnp_path)
+    return jnp_path(q, k, v)
+
+
+def chunked_attention(q, k, v, *, causal, window, q_offset, chunk_q,
+                      softcap):
+    """The jnp path of `full_attention` (same arguments).  When Sq > chunk_q
+    and divisible, scans over query chunks, so the f32 scores of one step
+    are [B, Hq, chunk_q, Sk]; otherwise they are [B, Hq, Sq, Sk] whole."""
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
     assert Hq % Hkv == 0, (Hq, Hkv)
@@ -101,6 +130,40 @@ def full_attention(
     _, outs = jax.lax.scan(body, None, (jnp.arange(n_chunks), qc))
     out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sq, Hq, D)
     return out
+
+
+def flash_block(S: int) -> int:
+    """The kernel's query and key block: the largest of 512, 256, 128 that
+    divides the sequence length S."""
+    return next(b for b in (512, 256, 128) if S % b == 0)
+
+
+def causal_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                           interpret: bool = False) -> jax.Array:
+    """Causal GQA self-attention as a flash kernel.
+
+    q: [B, S, Hq, D]; k, v: [B, S, Hkv, D] -> [B, S, Hq, D], as
+    `full_attention`.  Each (batch, KV head) is one MQA call of the splash
+    kernel over its Hq / Hkv query heads, so K/V are never repeated per
+    query head; blocks of `flash_block(S)`, f32 scores and accumulation,
+    the 1/sqrt(D) scale folded into q.  `interpret=True` runs it on the
+    CPU (tests)."""
+    B, S, Hq, D = q.shape
+    Hkv = k.shape[2]
+    G = Hq // Hkv
+    blk = flash_block(S)
+    sizes = splash.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=blk,
+        block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
+        block_q_dq=blk, block_kv_dq=blk)
+    mask = splash.MultiHeadMask([splash.CausalMask((S, S))] * G)
+    kernel = splash.make_splash_mqa_single_device(
+        mask, block_sizes=sizes, interpret=interpret)
+    q = q * jnp.asarray(1.0 / (D ** 0.5), q.dtype)
+    qh = q.reshape(B, S, Hkv, G, D).transpose(0, 2, 3, 1, 4)
+    o = jax.vmap(jax.vmap(kernel))(qh, k.transpose(0, 2, 1, 3),
+                                   v.transpose(0, 2, 1, 3))
+    return o.transpose(0, 3, 1, 2, 4).reshape(B, S, Hq, D)
 
 
 def decode_attention(

@@ -90,11 +90,20 @@ def head_matrix(cfg: ModelConfig, params: dict) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _project_qkv(cfg: ModelConfig, pl: dict, x: jax.Array):
-    """x [..., d] -> q [..., Hq, Dh], k/v [..., Hkv, Dh] (roped by caller)."""
-    q = jnp.einsum("...d,dhe->...he", x, pl["wq"])
-    k = jnp.einsum("...d,dhe->...he", x, pl["wk"])
-    v = jnp.einsum("...d,dhe->...he", x, pl["wv"])
+def _project_qkv(cfg: ModelConfig, pl: dict, x: jax.Array, *,
+                 head_major: bool = False):
+    """x [..., d] -> q [..., Hq, Dh], k/v [..., Hkv, Dh] (roped by caller).
+
+    head_major (x [B, S, d]): the same values, projected in [B, H, S, Dh]
+    order and viewed [B, S, H, Dh]; XLA then lays them out as the flash
+    kernel of `attention.full_attention` reads them, instead of copying
+    them into that layout."""
+    if head_major:
+        q, k, v = (jnp.einsum("bsd,dhe->bhse", x, pl[w]).swapaxes(1, 2)
+                   for w in ("wq", "wk", "wv"))
+    else:
+        q, k, v = (jnp.einsum("...d,dhe->...he", x, pl[w])
+                   for w in ("wq", "wk", "wv"))
     if cfg.qkv_bias:
         q, k, v = q + pl["bq"], k + pl["bk"], v + pl["bv"]
     if cfg.qk_norm:
@@ -108,7 +117,7 @@ def attention_full(cfg: ModelConfig, pl: dict, x: jax.Array, *,
     """Full-sequence attention sublayer.  Returns (y, k, v) — roped k and raw
     v for the cache."""
     B, S, _ = x.shape
-    q, k, v = _project_qkv(cfg, pl, x)
+    q, k, v = _project_qkv(cfg, pl, x, head_major=True)
     positions = q_offset + jnp.arange(S)
     q = rope(q, jnp.broadcast_to(positions, (B, S)), cfg.rope_theta)
     k = rope(k, jnp.broadcast_to(positions, (B, S)), cfg.rope_theta)

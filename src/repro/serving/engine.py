@@ -26,11 +26,13 @@ shares by nesting). Inside it, `engine.prefill` is everything before the
 first decode step and `engine.decode` the decode loop, made of one
 `engine.step` per decoded position; in a step, `engine.fetch` is the
 device-to-host copy of the token and `engine.wait` the meter's block on
-the device. `engine.compile` (arg `program`) is a new signature lowered
-and compiled. Wrapping a serve in `jax.profiler.trace(dir)` records these
-spans beside the chip's op and program events, for TensorBoard or
-Perfetto; the device's events may run a millisecond or so ahead of the
-host's clock there.
+the device. `engine.compile` is a new signature lowered and compiled,
+with args `program` and `attn_kernels`: the Mosaic kernel calls in the
+compiled program (1 for a dense prefill lowered for TPU, where attention
+runs as a flash kernel), counted only while a trace is recorded. Wrapping
+a serve in `jax.profiler.trace(dir)` records these spans beside the
+chip's op and program events, for TensorBoard or Perfetto; the device's
+events may run a millisecond or so ahead of the host's clock there.
 """
 
 from __future__ import annotations
@@ -100,13 +102,17 @@ class _CompiledFn:
                tuple(sorted(static.items())))
         exe = self._executables.get(key)
         if exe is None:
-            with jax.profiler.TraceAnnotation(COMPILE, program=self.name):
+            with jax.profiler.TraceAnnotation(COMPILE,
+                                              program=self.name) as span:
                 t0 = time.perf_counter()
                 lowered = self._jit.lower(*args, **static)
                 t1 = time.perf_counter()
                 exe = lowered.compile()
                 self.lower_s += t1 - t0
                 self.seconds += time.perf_counter() - t1
+                if span.is_enabled():
+                    span.set_metadata(attn_kernels=exe.as_text().count(
+                        'custom_call_target="tpu_custom_call"'))
             self.count += 1
             self._executables[key] = exe
         return exe

@@ -156,6 +156,15 @@ def test_compile_span_once_per_new_signature(calls, key, programs):
     assert got == programs
 
 
+@pytest.mark.parametrize("key", ["cached-cold", "ssm-cold", "uncached-cold"])
+def test_compile_span_counts_no_attention_kernel_on_cpu(calls, key):
+    # the flash attention kernel is lowered only for TPU
+    c = calls[key]
+    got = [a["attn_kernels"] for s, a in zip(c.spans, c.args)
+           if s.name == E.COMPILE]
+    assert got and got == [0] * len(got)
+
+
 @pytest.mark.parametrize("key", ALL)
 def test_generate_span_carries_the_batch(calls, key):
     c = calls[key]

@@ -1,6 +1,8 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles,
 executed with interpret=True (the CPU-container contract for TPU kernels)."""
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -159,3 +161,90 @@ class TestOpsWrappers:
         b = jnp.asarray(RNG.normal(size=(1, 64, 64)), jnp.float32)
         h = ops.rglru(a, b, block_s=32, block_w=64, interpret=True)
         assert h.shape == a.shape
+
+
+class TestCausalFlashPrefill:
+    """The flash kernel that `models.attention.full_attention` takes for
+    causal self-attention lowered for TPU, and the dispatch to it."""
+
+    B, HQ, HKV, D = 2, 16, 8, 128
+
+    def _qkv(self, S, dtype=jnp.bfloat16):
+        ks = jax.random.split(jax.random.PRNGKey(S), 3)
+        q = jax.random.normal(ks[0], (self.B, S, self.HQ, self.D), dtype)
+        k = jax.random.normal(ks[1], (self.B, S, self.HKV, self.D), dtype)
+        v = jax.random.normal(ks[2], (self.B, S, self.HKV, self.D), dtype)
+        return q, k, v
+
+    @pytest.mark.parametrize("S, block", [(256, 256), (768, 256),
+                                          (1280, 256), (1536, 512)])
+    def test_matches_jnp_attention(self, S, block):
+        from repro.models import attention as A
+
+        assert A.flash_block(S) == block
+        q, k, v = self._qkv(S)
+        want = A.chunked_attention(q, k, v, causal=True, window=0,
+                                   q_offset=0, chunk_q=512, softcap=0.0)
+        got = A.causal_flash_attention(q, k, v, interpret=True)
+        want = np.asarray(want, np.float32)
+        # Both paths keep f32 scores and sums but hand bf16 on at three
+        # points, each rounded to half a bf16 ulp: q after the folded
+        # 1/sqrt(D) (the jnp path scales f32 scores), the probabilities
+        # entering the PV product, and the output. Two ulps of the largest
+        # output bound their difference; the paths differ by one ulp here.
+        tol = 2 * float(jnp.finfo(jnp.bfloat16).eps) * np.abs(want).max()
+        np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                                   rtol=0, atol=tol)
+
+    def test_gradients_match_jnp_attention(self):
+        """A training step lowered for TPU differentiates through the
+        kernel's own backward kernels; in f32 they give the jnp path's
+        gradients to f32 rounding."""
+        from repro.models import attention as A
+
+        B, S, HQ, HKV, D = 1, 256, 4, 2, 128
+        ks = jax.random.split(jax.random.PRNGKey(1), 3)
+        q = jax.random.normal(ks[0], (B, S, HQ, D), jnp.float32)
+        k = jax.random.normal(ks[1], (B, S, HKV, D), jnp.float32)
+        v = jax.random.normal(ks[2], (B, S, HKV, D), jnp.float32)
+
+        def loss(attend):
+            return lambda q, k, v: jnp.sum(attend(q, k, v) ** 2)
+
+        flash = partial(A.causal_flash_attention, interpret=True)
+        grad = partial(jax.grad, argnums=(0, 1, 2))
+        lowered = jax.jit(grad(loss(A.full_attention))).trace(q, k, v).lower(
+            lowering_platforms=("tpu",))
+        assert lowered.as_text().count("tpu_custom_call") == 3  # fwd, dq, dkv
+        want = grad(loss(A.full_attention))(q, k, v)
+        got = grad(loss(flash))(q, k, v)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g, w, rtol=0,
+                                       atol=1e-5 * np.abs(w).max())
+
+    @pytest.mark.parametrize("S, kw, kernels", [
+        (256, {}, 1),
+        (256, {"window": 64}, 0),
+        (256, {"softcap": 30.0}, 0),
+        (256, {"causal": False}, 0),
+        (256, {"q_offset": 128}, 0),
+        (256, {"kv_len": 384}, 0),
+        (320, {}, 0),
+    ])
+    def test_dispatch(self, S, kw, kernels):
+        """Lowered for TPU, only causal self-attention with no window, soft
+        cap or offset whose length divides into 128 holds a Mosaic call;
+        every call gives what the jnp path gives, bit for bit, on the CPU."""
+        from repro.models import attention as A
+
+        kw = dict(kw)
+        q, _, _ = self._qkv(S)
+        _, k, v = self._qkv(kw.pop("kv_len", S))
+        args = dict(causal=True, window=0, q_offset=0, chunk_q=512,
+                    softcap=0.0) | kw
+        f = jax.jit(lambda q, k, v: A.full_attention(q, k, v, **kw))
+        hlo = f.trace(q, k, v).lower(lowering_platforms=("tpu",)).as_text()
+        assert hlo.count("tpu_custom_call") == kernels
+        jnp_path = jax.jit(lambda q, k, v: A.chunked_attention(q, k, v, **args))
+        np.testing.assert_array_equal(np.asarray(f(q, k, v), np.float32),
+                                      np.asarray(jnp_path(q, k, v), np.float32))

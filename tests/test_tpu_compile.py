@@ -4,8 +4,9 @@ The engine's prefill and decode programs for qwen3-1.7b and mamba2-130m at
 published widths go through the TPU compiler, which refuses what the chip
 cannot run, and each program's arguments, temporaries and outputs must fit
 the chip's 16 GB. A compile is not a run: nothing here says anything about
-results or times. One rehearsal also holds the compiled structure of
-mamba2's decode: its f32 state stack is updated in place.
+results or times. Two rehearsals also hold a compiled structure: mamba2's
+decode updates its f32 state stack in place, and qwen3's prefill runs its
+attention as a flash kernel, with no score tensor in device memory.
 """
 
 from __future__ import annotations
@@ -131,3 +132,26 @@ def test_ssm_decode_updates_state_in_place(one_chip):
     staged = [op for op in _unfused_ops(hlo)
               if " fusion(" in op and layer.search(op.split(" fusion(")[0])]
     assert not staged, staged
+
+
+# temporaries of the qwen3 prefill at B 16, prompt 1280, cache 1792 when its
+# jnp attention formed f32[16,8,2,1280,1280] scores whole
+JNP_ATTENTION_TEMP_1280 = 5_831_752_192
+
+
+@pytest.mark.parametrize("prompt, cache_len", [(1536, 2048), (1280, 1792)])
+def test_dense_prefill_attention_is_a_flash_kernel(one_chip, prompt, cache_len):
+    """qwen3-1.7b's prefill at the benchmark cell's batch (16) and prompt
+    lengths that take 512 and 256 blocks: the attention is a Mosaic call
+    and no f32 [B, Hkv, G, q, k] score tensor is left in the program."""
+    eng = _engine("qwen3-1.7b", one_chip)
+    tokens = _on(one_chip, (16, prompt), jnp.int32)
+    compiled = eng._prefill.executable(eng.params, {"tokens": tokens},
+                                       cache_len=cache_len, long_context=False)
+    _fits(compiled)
+    hlo = compiled.as_text()
+    assert not re.findall(r"f32\[16,8,2,\d+,\d+\]", hlo)
+    assert hlo.count('custom_call_target="tpu_custom_call"') >= 1
+    if prompt == 1280:
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < JNP_ATTENTION_TEMP_1280, temp
